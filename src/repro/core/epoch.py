@@ -399,7 +399,6 @@ def run_sharded(sample_fn: SampleFn, check_fn: CheckFn, template: PyTree,
     ``axis_index_groups`` (real collectives, no psum+slice fallback).
     """
     from jax.sharding import PartitionSpec as P
-    from .compat import shard_map
     from .frames import axis_collectives
 
     world = mesh.shape[axis]
@@ -416,8 +415,8 @@ def run_sharded(sample_fn: SampleFn, check_fn: CheckFn, template: PyTree,
 
     keys = jax.random.split(jax.random.key(seed), world)
     wids = jnp.arange(world, dtype=jnp.int32)
-    fn = shard_map(per_worker, mesh=mesh,
-                   in_specs=(P(axis), P(axis)),
-                   out_specs=P(axis),
-                   check_vma=False)
+    fn = jax.shard_map(per_worker, mesh=mesh,
+                       in_specs=(P(axis), P(axis)),
+                       out_specs=P(axis),
+                       check_vma=False)
     return fn(keys, wids)

@@ -8,9 +8,9 @@ SEQUENTIAL   W = 1, identity collectives — the correctness oracle.
 VMAP         W virtual workers on one device via ``vmap(axis_name=...)``;
              collectives are simulated (psum = sum over the mapped axis).
              This is how tests and the paper-figure benchmarks run on CPU.
-SHARD_MAP    W real devices on a mesh axis via ``shard_map`` (through the
-             :mod:`repro.core.compat` resolver); collectives lower to real
-             all-reduce / reduce-scatter / all-gather, and the SHARED_FRAME
+SHARD_MAP    W real devices on a mesh axis via ``jax.shard_map``;
+             collectives lower to real all-reduce / reduce-scatter /
+             all-gather, and the SHARED_FRAME
              F < W path uses the paper's grouped reduce-scatter +
              cross-group all-reduce (``axis_index_groups``) instead of the
              vmap psum+slice reference form.
@@ -86,7 +86,7 @@ def worker_mesh(world: int, axis: str = WORKER_AXIS, devices=None):
     so concurrent sessions must be buildable on e.g. devices ``[4..7]``).
     Default: the historical leading ``jax.devices()[:world]``.
     """
-    from .compat import make_mesh
+    from jax.sharding import AxisType
     if devices is None:
         reason = unavailable_reason(Substrate.SHARD_MAP, world)
         if reason is not None:
@@ -96,7 +96,8 @@ def worker_mesh(world: int, axis: str = WORKER_AXIS, devices=None):
     if len(devices) != world:
         raise ValueError(f"worker_mesh needs exactly world={world} devices, "
                          f"got {len(devices)}")
-    return make_mesh((world,), (axis,), devices=devices)
+    return jax.make_mesh((world,), (axis,), axis_types=(AxisType.Auto,),
+                         devices=devices)
 
 
 def mesh_device_ids(mesh) -> tuple:
@@ -231,11 +232,10 @@ def make_stepper(sample_fn, check_fn, template: PyTree, init_carry: PyTree,
     else:
         from jax.sharding import PartitionSpec as P
 
-        from .compat import shard_map
-
         def _mapped(fn):
-            return shard_map(fn, mesh=mesh, in_specs=(P(axis), P(axis)),
-                             out_specs=P(axis), check_vma=False)
+            return jax.shard_map(fn, mesh=mesh,
+                                 in_specs=(P(axis), P(axis)),
+                                 out_specs=P(axis), check_vma=False)
 
         def init_raw(seed_arr, keys):
             p = make_prog(seed_arr)

@@ -54,8 +54,10 @@ def bfs_sssp(g: Graph, s: jax.Array, t: jax.Array = None, *,
 
     def body(st):
         level, dist, sigma, _ = st
-        active = dist[g.src] == level
-        contrib = jnp.where(active, sigma[g.src], 0.0)
+        # one per-arc gather per level: the (arcs × samples) intermediate is
+        # the largest buffer of a batched BFS
+        frontier_sigma = jnp.where(dist == level, sigma, 0.0)
+        contrib = frontier_sigma[g.src]
         agg = jax.ops.segment_sum(contrib, g.dst, num_segments=n)
         newly = jnp.logical_and(dist == INF, agg > 0.0)
         dist = jnp.where(newly, level + 1, dist)

@@ -4,14 +4,13 @@
 |-------------------|---------------------------------------------------------|
 | ``frame_accum``   | Θ(T·n) state-frame accumulation (Alg. 2 line 27)        |
 | ``bfs_frontier``  | one BFS level of SAMPLE() (CSR frontier expansion)      |
-| ``alias_draw``    | batched alias-table draws (weighted sampling SAMPLE())  |
 | ``flash_attention``| prefill/train attention with causal/window block skip  |
 | ``ssm_scan``      | Mamba selective-scan recurrence                         |
 | ``rglru_scan``    | RG-LRU gated linear recurrence                          |
 
-``ops.py`` exposes jit'd wrappers (with ``interpret=`` switch: CPU validation
-runs the kernel body in python); ``ref.py`` holds the pure-jnp oracles every
-kernel is tested against across shape/dtype sweeps.
+``ops.py`` exposes wrappers that run each kernel compiled on a TPU and its
+pure-jnp oracle elsewhere; ``ref.py`` holds those oracles, which every kernel
+is tested against (``interpret=True``) across shape/dtype sweeps.
 """
 from . import ops, ref  # noqa: F401
 

@@ -1,9 +1,8 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the wrappers default to ``interpret=True`` — the
-kernel body executes in python for correctness validation.  On a TPU backend
-they run compiled.  ``use_kernels(False)`` (or backend ≠ tpu) falls back to
-the pure-jnp oracles so the model code can call one entry point everywhere.
+On a TPU backend every wrapper runs its kernel compiled; on any other
+backend it runs the pure-jnp oracle from ``ref.py``.  Interpret mode is for
+tests only, which call the kernel modules directly.
 """
 
 from __future__ import annotations
@@ -11,75 +10,42 @@ from __future__ import annotations
 import jax
 
 from . import ref as _ref
-from .alias_draw import alias_draw as _alias_kernel
 from .bfs_frontier import bfs_frontier as _bfs_kernel
 from .flash_attention import flash_attention as _fa_kernel
 from .frame_accum import frame_accum as _fa_accum_kernel
 from .rglru_scan import rglru_scan as _rg_kernel
 from .ssm_scan import ssm_scan as _ssm_kernel
 
-_FORCE: bool | None = None
-
-
-def use_kernels(enable: bool | None) -> None:
-    """Force kernels on/off (None → auto: on for TPU backends)."""
-    global _FORCE
-    _FORCE = enable
-
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _kernel_mode() -> str:
-    """'compiled' | 'interpret' | 'ref'."""
-    if _FORCE is False:
-        return "ref"
-    if _on_tpu():
-        return "compiled"
-    if _FORCE:
-        return "interpret"
-    return "ref"
-
-
 def frame_accum(frames):
-    mode = _kernel_mode()
-    if mode == "ref":
+    if not _on_tpu():
         return _ref.frame_accum_ref(frames)
-    return _fa_accum_kernel(frames, interpret=mode == "interpret")
+    return _fa_accum_kernel(frames)
 
 
 def flash_attention(q, k, v, *, window: int = 0):
-    mode = _kernel_mode()
-    if mode == "ref":
+    if not _on_tpu():
         return _ref.flash_attention_ref(q, k, v, window=window)
-    return _fa_kernel(q, k, v, window=window, interpret=mode == "interpret")
+    return _fa_kernel(q, k, v, window=window)
 
 
 def ssm_scan(a, b):
-    mode = _kernel_mode()
-    if mode == "ref":
+    if not _on_tpu():
         return _ref.ssm_scan_ref(a, b)
-    return _ssm_kernel(a, b, interpret=mode == "interpret")
+    return _ssm_kernel(a, b)
 
 
 def rglru_scan(a, b):
-    mode = _kernel_mode()
-    if mode == "ref":
+    if not _on_tpu():
         return _ref.rglru_scan_ref(a, b)
-    return _rg_kernel(a, b, interpret=mode == "interpret")
+    return _rg_kernel(a, b)
 
 
 def bfs_frontier(src, dst, sigma, dist, level):
-    mode = _kernel_mode()
-    if mode == "ref":
+    if not _on_tpu():
         return _ref.bfs_frontier_ref(src, dst, sigma, dist, level)
-    return _bfs_kernel(src, dst, sigma, dist, level,
-                       interpret=mode == "interpret")
-
-
-def alias_draw(prob, alias, u1, u2):
-    mode = _kernel_mode()
-    if mode == "ref":
-        return _ref.alias_draw_ref(prob, alias, u1, u2)
-    return _alias_kernel(prob, alias, u1, u2, interpret=mode == "interpret")
+    return _bfs_kernel(src, dst, sigma, dist, level)

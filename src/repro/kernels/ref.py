@@ -51,11 +51,3 @@ def bfs_frontier_ref(src: jax.Array, dst: jax.Array, sigma: jax.Array,
     contrib = jnp.where(dist[src] == level, sigma.astype(jnp.float32)[src],
                         0.0)
     return jax.ops.segment_sum(contrib, dst, num_segments=sigma.shape[0])
-
-
-def alias_draw_ref(prob: jax.Array, alias: jax.Array, u1: jax.Array,
-                   u2: jax.Array) -> jax.Array:
-    """Batched alias-table draw: keep bucket ⌊u₁·n⌋ w.p. prob, else alias."""
-    n = prob.shape[0]
-    bucket = jnp.minimum((u1 * n).astype(jnp.int32), n - 1)
-    return jnp.where(u2 < prob[bucket], bucket, alias[bucket])

@@ -1,16 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
-# --- everything below may import jax -------------------------------------
-import argparse      # noqa: E402
-import dataclasses   # noqa: E402
-import json          # noqa: E402
-import time          # noqa: E402
-import traceback     # noqa: E402
-from pathlib import Path  # noqa: E402
-
-import jax           # noqa: E402
-
 """Multi-pod dry-run (deliverable e).
 
 For every (architecture × input shape × mesh) cell:
@@ -31,13 +18,22 @@ Usage:
     python -m repro.launch.dryrun --all --multipod # 2-pod mesh pass
 """
 
+import argparse
+import dataclasses
+import json
+import os
+import time
+import traceback
+from pathlib import Path
+
+import jax
+
 RESULTS = Path(__file__).resolve().parents[3] / "benchmarks" / "results" / "dryrun"
 
 
 def _cost_dict(compiled, chips: int) -> dict:
     from repro.analysis.hlo import collective_bytes
-    from repro.core.compat import cost_analysis
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     coll = collective_bytes(compiled.as_text())
     return {
         "flops": float(ca.get("flops", 0.0)),
@@ -78,8 +74,7 @@ def _lower_compile(cfg, shape, mesh, verbose=True, flags=None):
     # the cache one-hot update into the donated buffer.
     donate = {"train": (0, 1), "decode": (1,), "prefill": ()}[shape.kind]
     t0 = time.time()
-    from repro.core.compat import set_mesh
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step, in_shardings=in_sh,
                           donate_argnums=donate).lower(*args)
         t_lower = time.time() - t0
@@ -89,8 +84,7 @@ def _lower_compile(cfg, shape, mesh, verbose=True, flags=None):
     if verbose:
         print(f"  lowered {t_lower:.1f}s, compiled {t_compile:.1f}s")
         print(f"  memory_analysis: {compiled.memory_analysis()}")
-        from repro.core.compat import cost_analysis
-        ca = cost_analysis(compiled)
+        ca = compiled.cost_analysis()
         print(f"  cost_analysis: flops={ca.get('flops', 0):.4g} "
               f"bytes={ca.get('bytes accessed', 0):.4g}")
     return compiled, dict(t_lower=t_lower, t_compile=t_compile)
@@ -262,4 +256,6 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    # before the first backend use; importing this module changes nothing
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     raise SystemExit(main())

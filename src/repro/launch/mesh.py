@@ -1,26 +1,29 @@
 """Production meshes.
 
 ``make_production_mesh`` is a FUNCTION (not a module constant) so importing
-this module never touches jax device state.  The dry-run sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
-import; tests and benches see the real single device.
+this module never touches jax device state.  The dry-run's ``__main__``
+sets ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before the
+backend starts; tests and benches see the real single device.
 """
 
 from __future__ import annotations
 
-from ..core.compat import make_mesh as _compat_make_mesh
+import jax
+from jax.sharding import AxisType
+
 from ..core.substrate import WORKER_AXIS, worker_mesh as _worker_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh (tests use small ones, e.g. (2,2))."""
-    return _compat_make_mesh(shape, axes)
+    """Arbitrary mesh with ``Auto`` axes (tests use small ones, e.g. (2,2))."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(tuple(axes)))
 
 
 def make_worker_mesh(world: int, axis: str = WORKER_AXIS, devices=None):

@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..core.compat import shard_map
-
 PyTree = Any
 
 
@@ -82,6 +80,6 @@ def pipeline_forward(layer_fn: Callable, stacked_params: PyTree,
 
     # stage s holds layers [s·L/S, (s+1)·L/S)
     in_specs = (jax.tree.map(lambda _: P(axis), stacked_params), P())
-    fn = shard_map(stage_fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=P(), check_vma=False)
+    fn = jax.shard_map(stage_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=P(), check_vma=False)
     return fn(stacked_params, x_micro)

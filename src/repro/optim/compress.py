@@ -17,8 +17,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..core.compat import axis_size
-
 PyTree = Any
 
 
@@ -44,7 +42,7 @@ def compressed_psum(grads: PyTree, ef: PyTree, key: jax.Array,
     Returns (reduced f32 grads ≈ mean over axis, new error-feedback state).
     Scales are max-combined across the axis so the int8 grids agree.
     """
-    world = axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     leaves, treedef = jax.tree_util.tree_flatten(grads)
     ef_leaves = jax.tree_util.tree_leaves(ef)
     keys = jax.random.split(key, len(leaves))

@@ -103,17 +103,23 @@ def weighted_mean_exact(weights: np.ndarray, values_q: np.ndarray,
     return float((w * x).sum() / w.sum())
 
 
+def alias_draw(prob: jax.Array, alias: jax.Array, u1: jax.Array,
+               u2: jax.Array) -> jax.Array:
+    """Batched alias-table draws: keep bucket ⌊u₁·n⌋ w.p. ``prob``, else
+    ``alias``.  Two XLA gathers on every backend (a TPU Pallas kernel cannot
+    index a VMEM table with a vector of indices)."""
+    n = prob.shape[0]
+    bucket = jnp.minimum((u1 * n).astype(jnp.int32), n - 1)
+    return jnp.where(u2 < prob[bucket], bucket, alias[bucket])
+
+
 def make_weighted_sample_fn(table: AliasTable, values_q: jax.Array,
                             batch: int, *, pad_to: Optional[int] = None):
     """Build SAMPLE() — one vectorized round of ``batch`` alias draws.
 
-    The draw itself goes through :func:`repro.kernels.ops.alias_draw`
-    (Pallas on TPU, pure-jnp oracle elsewhere); uniforms only *select*
-    integer indices, so the accumulated frame is integer-exact and
-    identical across strategies for identical keys.
+    Uniforms only *select* integer indices, so the accumulated frame is
+    integer-exact and identical across strategies for identical keys.
     """
-    from ..kernels import ops
-
     n = table.n
     n_pad = pad_to or n
     values_q = jnp.asarray(values_q, jnp.int32)
@@ -122,7 +128,7 @@ def make_weighted_sample_fn(table: AliasTable, values_q: jax.Array,
         k1, k2 = jax.random.split(key)
         u1 = jax.random.uniform(k1, (batch,))
         u2 = jax.random.uniform(k2, (batch,))
-        idx = ops.alias_draw(table.prob, table.alias, u1, u2)
+        idx = alias_draw(table.prob, table.alias, u1, u2)
         xq = values_q[idx]
         hist = jax.ops.segment_sum(jnp.ones((batch,), jnp.int32), idx,
                                    num_segments=n_pad)

@@ -7,7 +7,6 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.analysis.hlo import collective_bytes, parse_shape_bytes
-from repro.core.compat import shard_map
 from repro.analysis.roofline import (combine_layer_diff, model_flops,
                                      roofline_terms)
 from repro.models import SHAPES, get_config
@@ -63,7 +62,7 @@ def test_collective_bytes_on_real_hlo():
     def f(a):
         return jax.lax.psum(a, "x")
 
-    g = jax.jit(shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P()))
+    g = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P()))
     txt = g.lower(jnp.ones((8, 128), jnp.float32)).compile().as_text()
     out = collective_bytes(txt)
     # single-device psum may be optimized away; at minimum the parser

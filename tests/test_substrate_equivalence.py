@@ -121,7 +121,6 @@ assert "shard_map" in grouped.ran and "vmap" in grouped.ran
 # reduce-scatter (axis_index_groups), not the psum+slice reference form.
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.core.compat import shard_map
 from repro.core.frames import StateFrame, axis_collectives
 from repro.core.substrate import worker_mesh
 
@@ -133,8 +132,8 @@ def scatter(x):
     out = colls.scatter_frames(f)
     return out.data[None]
 
-fn = shard_map(scatter, mesh=mesh, in_specs=P("workers"),
-               out_specs=P("workers"), check_vma=False)
+fn = jax.shard_map(scatter, mesh=mesh, in_specs=P("workers"),
+                   out_specs=P("workers"), check_vma=False)
 text = jax.jit(fn).lower(jnp.zeros((4, 8), jnp.int32)).as_text()
 assert "reduce_scatter" in text, "grouped path must lower to reduce_scatter"
 print("GROUPED_SUBSTRATE_OK")
@@ -150,7 +149,6 @@ def test_grouped_lowering_emits_reduce_scatter():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.compat import shard_map
     from repro.core.frames import StateFrame, axis_collectives
     from repro.core.substrate import worker_mesh
 
@@ -161,8 +159,8 @@ def test_grouped_lowering_emits_reduce_scatter():
         out = colls.scatter_frames(StateFrame(num=jnp.int32(1), data=x[0]))
         return out.data[None]
 
-    fn = shard_map(scatter, mesh=mesh, in_specs=P("workers"),
-                   out_specs=P("workers"), check_vma=False)
+    fn = jax.shard_map(scatter, mesh=mesh, in_specs=P("workers"),
+                       out_specs=P("workers"), check_vma=False)
     text = jax.jit(fn).lower(jnp.zeros((4, 8), jnp.int32)).as_text()
     assert "reduce_scatter" in text
 

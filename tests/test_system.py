@@ -71,6 +71,28 @@ def test_mini_dryrun_subprocess():
     assert len(jax.devices()) == 1  # flag must not leak
 
 
+def test_dryrun_import_leaves_xla_flags(monkeypatch):
+    """Only the dry-run's ``__main__`` forces 512 host devices."""
+    import importlib
+    monkeypatch.setenv("XLA_FLAGS", "--xla_force_host_platform_device_count=1")
+    importlib.reload(importlib.import_module("repro.launch.dryrun"))
+    assert os.environ["XLA_FLAGS"] == "--xla_force_host_platform_device_count=1"
+
+
+@pytest.mark.parametrize("build", ["worker_mesh", "make_mesh"])
+def test_meshes_have_auto_axes(build):
+    """jax.make_mesh defaults to Explicit axes; the engine's meshes are
+    Auto so shard_map and jit shard as before."""
+    from jax.sharding import AxisType
+    if build == "worker_mesh":
+        from repro.core.substrate import worker_mesh
+        mesh = worker_mesh(1)
+    else:
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 1), ("data", "model"))
+    assert set(mesh.axis_types) == {AxisType.Auto}
+
+
 def test_sharded_epoch_engine_on_mesh():
     """run_sharded on a 1-device mesh (semantics identical to vmap path)."""
     from repro.core.epoch import EpochConfig, run_sharded
