@@ -33,6 +33,7 @@ from benchmarks.common import emit, instances, timeit
 from repro.core.frames import FrameStrategy
 from repro.graphs import (KadabraParams, frame_template, make_sample_fn,
                           preprocess, run_kadabra)
+from repro.graphs.kadabra import init_counters
 
 
 def measured_overheads():
@@ -64,7 +65,7 @@ def simulated_scaling(g, pre, n_events: int = 400, seed: int = 0):
     # measure S (one sampling round), R per element, C per element
     key = jax.random.key(0)
     s_cost = timeit(lambda: jax.jit(
-        lambda k: sample_fn(k, None)[0].data)(key), iters=3)
+        lambda k: sample_fn(k, init_counters())[0].data)(key), iters=3)
     n = g.n
     red = jax.jit(lambda x: jnp.sum(x, 0))
     r_cost_4 = timeit(lambda: red(jnp.ones((4, n), jnp.int32)), iters=3)

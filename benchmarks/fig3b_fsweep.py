@@ -14,6 +14,7 @@ from repro.core.epoch import EpochConfig, run_virtual
 from repro.core.frames import FrameStrategy, shard_frame_pad
 from repro.core.stopping import KadabraCondition
 from repro.graphs import frame_template, make_sample_fn, preprocess
+from repro.graphs.kadabra import init_counters
 
 
 def run() -> None:
@@ -28,7 +29,8 @@ def run() -> None:
         cfg = EpochConfig(strategy=FrameStrategy.SHARED_FRAME,
                           rounds_per_epoch=4, max_epochs=3000)
         t = timeit(lambda F=F, pad=pad, s=sample_fn, c=cond, cf=cfg:
-                   run_virtual(s, c, frame_template(g, pad), None, 0, W, cf,
+                   run_virtual(s, c, frame_template(g, pad), init_counters(),
+                               0, W, cf,
                                frame_shards=F).total.num,
                    warmup=1, iters=2)
         mem_per_worker = pad // F * 4  # int32 shard bytes

@@ -19,7 +19,7 @@ from repro.checkpoint import CheckpointManager
 from repro.data import DataCursor, TokenStream
 from repro.models import Model
 from repro.optim.adamw import adamw_init
-from repro.runtime import elastic_restore
+from repro.serve.elastic import elastic_restore
 import repro.configs.smollm_360m as sm
 
 

@@ -183,6 +183,12 @@ def run_conformance(instance: "str | AdaptiveInstance", *,
         if not _tree_equal(data_l, data_s):
             cross.append(f"W={w}: SHARED shard reassembly ≠ LOCAL total")
 
+    # Every cell ran freshly built closures, so none of the sweep's compiled
+    # programs can be hit again; JAX would keep them, and the memory
+    # mappings of their code, for the life of the process.  A few sweeps in
+    # one process reach the kernel's limit on mappings, and the next
+    # compile then crashes.
+    jax.clear_caches()
     name = inst.name if not isinstance(instance, str) else instance
     return ConformanceReport(instance=name, cells=cells, cross_failures=cross)
 

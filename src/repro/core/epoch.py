@@ -186,6 +186,9 @@ def make_program(
         )(k_epoch, carry)
         return accumulate(frames), carry
 
+    # the exchange of frames between workers and the stop check are named
+    # scopes, so a trace can tell them from the sampling around them
+    @jax.named_scope("stop_check")
     def check_full(total: StateFrame):
         stop, aux = check_fn(total)
         if W > 1:
@@ -195,6 +198,7 @@ def make_program(
             stop = colls.reduce_scalar(stop.astype(jnp.int32)) >= W
         return stop, aux
 
+    @jax.named_scope("stop_check")
     def check_sharded(total_shard: StateFrame):
         stop_local, aux = check_fn(total_shard)
         stop = colls.reduce_scalar(stop_local.astype(jnp.int32)) >= W
@@ -207,7 +211,8 @@ def make_program(
         def body(st: EpochState, worker_id) -> EpochState:
             k_epoch, key = split_keys(st.key)
             delta, carry = sample_epoch(k_epoch, st.carry, rounds)
-            reduced = colls.reduce_frames(delta)          # blocking barrier
+            with jax.named_scope("frame_exchange"):
+                reduced = colls.reduce_frames(delta)      # blocking barrier
             total = combine(st.total, reduced)
             stop, aux = check_full(total)
             e = st.epoch + 1
@@ -220,7 +225,8 @@ def make_program(
         def body(st: EpochState, worker_id) -> EpochState:
             # (a) fold in the PREVIOUS epoch's deltas — no data dependency on
             # (b), so the all-reduce can overlap the sampling compute.
-            reduced = colls.reduce_frames(st.pending)
+            with jax.named_scope("frame_exchange"):
+                reduced = colls.reduce_frames(st.pending)
             total = combine(st.total, reduced)
             stop, aux = check_full(total)
             # (b) sample the current epoch.
@@ -235,7 +241,8 @@ def make_program(
         assert colls.scatter_frames is not None, "SHARED_FRAME needs scatter_frames"
 
         def body(st: EpochState, worker_id) -> EpochState:
-            reduced_shard = colls.scatter_frames(st.pending)
+            with jax.named_scope("frame_exchange"):
+                reduced_shard = colls.scatter_frames(st.pending)
             total = combine(st.total, reduced_shard)
             stop, aux = check_sharded(total)
             k_epoch, key = split_keys(st.key)
@@ -256,13 +263,15 @@ def make_program(
             return _sample_epoch(sample_fn, template, cfg.rounds_per_epoch, k, carry)
 
         def body(st: EpochState, worker_id) -> EpochState:
-            gathered = colls.all_frames(st.pending)   # (W, ...) per-frame deltas
+            with jax.named_scope("frame_exchange"):   # (W, ...) per-frame deltas
+                gathered = colls.all_frames(st.pending)
 
             def prefix_step(acc, j):
                 total, stop, aux, stop_epoch = acc
                 fj = jax.tree.map(lambda x: x[j], gathered)
                 total_j = combine(total, fj)
-                s_j, aux_j = check_fn(total_j)
+                with jax.named_scope("stop_check"):
+                    s_j, aux_j = check_fn(total_j)
                 # freeze at the FIRST stopping prefix (determinism).
                 first = s_j & ~stop
                 total = jax.tree.map(lambda new, old: jnp.where(stop, old, new),
@@ -276,7 +285,8 @@ def make_program(
                 prefix_step, (st.total, st.stop, st.aux, st.stop_epoch),
                 jnp.arange(W))
             if W > 1:  # verdicts agree (same data), keep them in lockstep
-                stop = colls.reduce_scalar(stop.astype(jnp.int32)) >= W
+                with jax.named_scope("stop_check"):
+                    stop = colls.reduce_scalar(stop.astype(jnp.int32)) >= W
             delta, carry = sample_indexed(st.epoch, worker_id, st.carry)
             return EpochState(st.key, carry, total, delta, stop, aux,
                               st.epoch + 1, stop_epoch)

@@ -189,7 +189,8 @@ class KadabraInstance:
               strategy: FrameStrategy = FrameStrategy.LOCAL_FRAME
               ) -> BuiltInstance:
         from ..core.stopping import KadabraCondition
-        from ..graphs.kadabra import frame_template, make_sample_fn
+        from ..graphs.kadabra import (frame_template, init_counters,
+                                      make_sample_fn)
         g, pre, oracle = self._graph()
         pad = _pad_for(g.n, world, strategy)
         sample_fn = make_sample_fn(g, pre, self.batch, pad_to=pad)
@@ -201,7 +202,7 @@ class KadabraInstance:
 
         return BuiltInstance(
             name=self.name, sample_fn=sample_fn, check_fn=cond,
-            template=frame_template(g, pad), init_carry=None,
+            template=frame_template(g, pad), init_carry=init_counters(),
             samples_per_round=self.batch, true_len=g.n,
             eps=self.eps, delta=self.delta, oracle=oracle,
             estimate=estimate, rounds_per_epoch=self.rounds_per_epoch,

@@ -118,8 +118,9 @@ class EpochStepper:
     the seed as a traced scalar, so the serving scheduler can cache ONE
     stepper per session *shape* (instance config × strategy × W × F ×
     substrate × fold) and run any number of differently-seeded queries
-    through it without recompiling.  ``active(state)`` is the host-side
-    continuation predicate (all workers' verdicts are in lockstep).
+    through it without recompiling.  ``readback(state)`` brings the
+    host-side continuation predicate (all workers' verdicts are in
+    lockstep) and the sampler's counters to the host in one transfer.
 
     The invariant that makes checkpoint/resume and scheduling sound:
     ``step^n(init(seed))`` is bit-identical to the fused ``while_loop`` run
@@ -142,16 +143,22 @@ class EpochStepper:
         import jax.numpy as jnp
         return self.step_fn(state, jnp.asarray(seed, jnp.uint32))
 
-    def active(self, state) -> bool:
+    def readback(self, state) -> tuple:
+        """``(active, counters)``: whether the query goes on, and the
+        sampler's counters summed over workers.  The sampler's carry holds
+        counters where it is a dict of integers (``{}`` otherwise)."""
         import numpy as np
-        stop = bool(np.asarray(state.stop).reshape(-1)[0])
-        epoch = int(np.asarray(state.epoch).reshape(-1)[0])
-        return (not stop) and epoch < self.cfg.max_epochs
+        counters = state.carry if isinstance(state.carry, dict) else {}
+        stop, epoch, counters = jax.device_get(
+            (state.stop, state.epoch, counters))
+        active = (not bool(np.reshape(stop, -1)[0])
+                  and int(np.reshape(epoch, -1)[0]) < self.cfg.max_epochs)
+        return active, {k: int(np.sum(v)) for k, v in counters.items()}
 
     def run(self, seed: int):
         """Host-driven run to completion (the stepping-path oracle)."""
         st = self.init(seed)
-        while self.active(st):
+        while self.readback(st)[0]:
             st = self.step(st, seed)
         return st
 

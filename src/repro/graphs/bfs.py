@@ -33,12 +33,15 @@ _SIGMA_CAP = 1e30
 @partial(jax.jit, static_argnames=("max_levels", "early_exit"))
 def bfs_sssp(g: Graph, s: jax.Array, t: jax.Array = None, *,
              max_levels: int, early_exit: bool = True
-             ) -> Tuple[jax.Array, jax.Array]:
+             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Distances and (rescaled) shortest-path counts from ``s``.
 
-    Returns ``dist (n,) int32`` (INF if unreachable) and ``sigma (n,) f32``.
-    If ``early_exit`` and ``t`` is given, stops once t's level is complete
+    Returns ``dist (n,) int32`` (INF if unreachable), ``sigma (n,) f32``
+    and ``levels``, the int32 number of levels the loop ran.  If
+    ``early_exit`` and ``t`` is given, stops once t's level is complete
     (σ(t) is final at that point — all its predecessors are one level up).
+    Under ``vmap`` each lane's ``levels`` is its own; the batched loop runs
+    the largest.  The loop's body is the named scope ``bfs_level``.
     """
     n = g.n
     dist = jnp.full((n,), INF, jnp.int32).at[s].set(0)
@@ -52,6 +55,7 @@ def bfs_sssp(g: Graph, s: jax.Array, t: jax.Array = None, *,
             go = jnp.logical_and(go, jnp.where(t >= 0, dist[t] == INF, True))
         return go
 
+    @jax.named_scope("bfs_level")
     def body(st):
         level, dist, sigma, _ = st
         # one per-arc gather per level: the (arcs × samples) intermediate is
@@ -69,14 +73,14 @@ def bfs_sssp(g: Graph, s: jax.Array, t: jax.Array = None, *,
         sigma = jnp.where(newly, agg * scale, sigma)
         return (level + 1, dist, sigma, jnp.sum(newly.astype(jnp.int32)))
 
-    _, dist, sigma, _ = jax.lax.while_loop(
+    levels, dist, sigma, _ = jax.lax.while_loop(
         cond, body, (jnp.int32(0), dist, sigma, jnp.int32(1)))
-    return dist, sigma
+    return dist, sigma, levels
 
 
 @partial(jax.jit, static_argnames=("max_levels",))
 def eccentricity(g: Graph, s: jax.Array, *, max_levels: int) -> jax.Array:
-    dist, _ = bfs_sssp(g, s, None, max_levels=max_levels, early_exit=False)
+    dist, _, _ = bfs_sssp(g, s, None, max_levels=max_levels, early_exit=False)
     return jnp.max(jnp.where(dist == INF, 0, dist))
 
 
@@ -111,12 +115,15 @@ def sample_path(g: Graph, key: jax.Array, s: jax.Array, t: jax.Array,
     dist[u] = dist[cur]−1) with probability σ(u)/Σσ via Gumbel-max over the
     ≤ max_degree padded neighbor slots.  If t is unreachable the mask is all
     False (the sample contributes x_i = 0 — the correct estimator term).
+    The walk takes ``max_len`` steps, each the named scope ``path_step``;
+    the first ``dist[t]`` of them are live, the rest keep the walk at s.
     """
     n = g.n
     reachable = dist[t] != INF
     dist_pad = jnp.concatenate([dist, jnp.full((1,), INF, jnp.int32)])
     sigma_pad = jnp.concatenate([sigma, jnp.zeros((1,), jnp.float32)])
 
+    @jax.named_scope("path_step")
     def step(carry, k):
         cur, mask = carry
         done = jnp.logical_or(cur == s, ~reachable)

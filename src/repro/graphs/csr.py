@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..runtime.spans import span
+
 
 @partial(jax.tree_util.register_dataclass,
          data_fields=("indptr", "indices_padded", "src", "dst"),
@@ -42,10 +44,12 @@ class Graph:
         return jnp.where(slot < self.degree(v), nbrs, jnp.int32(self.n))
 
 
+@span("graph.csr")
 def from_edges(n: int, edges: np.ndarray) -> Graph:
     """Build an undirected simple Graph from an (E,2) int array of edges.
 
     Self-loops and duplicate edges are removed; each edge becomes two arcs.
+    Returns once the arrays are on the device.
     """
     edges = np.asarray(edges, dtype=np.int64).reshape(-1, 2)
     e = edges[edges[:, 0] != edges[:, 1]] if edges.size else edges
@@ -66,7 +70,8 @@ def from_edges(n: int, edges: np.ndarray) -> Graph:
     max_degree = max(int((indptr[1:] - indptr[:-1]).max(initial=1)), 1)
     # sentinel-pad the indices tail so dynamic_slice(start, max_degree) is safe
     indices_padded = np.concatenate([dst_s, np.full(max_degree, n, np.int32)])
-    return Graph(n=n, m_arcs=int(src_s.size), max_degree=max_degree,
-                 indptr=jnp.asarray(indptr),
-                 indices_padded=jnp.asarray(indices_padded),
-                 src=jnp.asarray(src_s), dst=jnp.asarray(dst_s))
+    return jax.block_until_ready(Graph(
+        n=n, m_arcs=int(src_s.size), max_degree=max_degree,
+        indptr=jnp.asarray(indptr),
+        indices_padded=jnp.asarray(indices_padded),
+        src=jnp.asarray(src_s), dst=jnp.asarray(dst_s)))

@@ -74,11 +74,11 @@ def diameter_exact(g: Graph) -> int:
 def double_sweep(g: Graph, v: jax.Array, *, max_levels: int
                  ) -> Tuple[jax.Array, jax.Array]:
     """One double sweep from v → (ecc(v), ecc(u)) with u = argmax dist(v,·)."""
-    dist_v, _ = bfs_sssp(g, v, None, max_levels=max_levels, early_exit=False)
+    dist_v, _, _ = bfs_sssp(g, v, None, max_levels=max_levels, early_exit=False)
     fin_v = jnp.where(dist_v == INF, -1, dist_v)
     u = jnp.argmax(fin_v).astype(jnp.int32)
     ecc_v = jnp.maximum(jnp.max(fin_v), 0)
-    dist_u, _ = bfs_sssp(g, u, None, max_levels=max_levels, early_exit=False)
+    dist_u, _, _ = bfs_sssp(g, u, None, max_levels=max_levels, early_exit=False)
     ecc_u = jnp.max(jnp.where(dist_u == INF, 0, dist_u))
     return ecc_v, ecc_u
 

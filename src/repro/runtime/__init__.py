@@ -1,4 +1,3 @@
 from .failures import FailureInjector, FailureEvent, Heartbeat
-from .elastic import elastic_restore
 
-__all__ = ["FailureInjector", "FailureEvent", "Heartbeat", "elastic_restore"]
+__all__ = ["FailureInjector", "FailureEvent", "Heartbeat"]

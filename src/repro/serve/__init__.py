@@ -14,8 +14,7 @@ Three pieces:
   max-in-flight admission policy and per-query τ accounting.
 * :mod:`repro.serve.elastic` — elastic re-sharding of SHARED_FRAME sessions
   (resume at a different worker width W′ | W, bit-identical (τ, estimate)),
-  plus the train-side :func:`elastic_restore` absorbed from
-  ``runtime/elastic.py``.
+  plus the train-side :func:`elastic_restore`.
 * :mod:`repro.serve.placement` — the device-topology pool: carve pairwise-
   disjoint submeshes with lease/release semantics so concurrent sessions
   run on *different* devices instead of contending for the leading ones,

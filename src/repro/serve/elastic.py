@@ -22,8 +22,7 @@ verdict are unchanged, the resumed run's (τ, estimate) is **bit-identical**
 to the uninterrupted W-worker run — certified by
 ``tests/test_serve_session.py``.
 
-Also home to the train-side :func:`elastic_restore` (absorbed from the seed
-stub ``runtime/elastic.py``, which remains as a deprecation shim): restore a
+Also home to the train-side :func:`elastic_restore`: restore a
 model/optimizer checkpoint distributed per the *new* mesh's shardings.
 """
 
@@ -178,4 +177,5 @@ def reshard_session(session: AdaptiveSession, new_world: int, *,
         session.state, old_spec=spec, new_spec=new_spec,
         template_state=resharded.state_template())
     resharded.wall_s = session.wall_s
+    resharded.query = session.query
     return resharded

@@ -51,6 +51,7 @@ from typing import Deque, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..runtime.spans import span
 from .elastic import reshard_session
 from .placement import DevicePool, Lease, PlacementWait, PressurePolicy
 from .session import AdaptiveSession, SessionSpec, StepperCache
@@ -200,25 +201,30 @@ class EpochScheduler:
         self._devices_peak[qid] = max(self._devices_peak.get(qid, 0),
                                       lease.width)
 
-    def _materialize(self, item, lease: Optional[Lease]) -> AdaptiveSession:
+    def _materialize(self, qid: str, item,
+                     lease: Optional[Lease]) -> AdaptiveSession:
         """Turn a queue entry into a started session bound to its lease."""
         ids = None if lease is None else lease.ids
         if isinstance(item, _Restore):
             spec = item.spec
-            if spec.substrate == "shard_map" and ids is not None \
-                    and ids != spec.placement:
-                return AdaptiveSession.restore(item.path, cache=self.cache,
-                                               placement=ids)
-            return AdaptiveSession.restore(item.path, cache=self.cache)
+            placement = ids if spec.substrate == "shard_map" \
+                and ids is not None and ids != spec.placement else "keep"
+            session = AdaptiveSession.restore(item.path, cache=self.cache,
+                                              placement=placement)
+            session.query = qid
+            return session
         if isinstance(item, AdaptiveSession):
             if item.spec.substrate == "shard_map" and ids is not None \
                     and ids != item.spec.placement:
                 item.rebind_placement(ids, cache=self.cache)
+            item.query = qid
             return item               # restored mid-run; already started
         spec = item
         if spec.substrate == "shard_map" and ids is not None:
             spec = dataclasses.replace(spec, placement=ids)
-        return AdaptiveSession.create(spec, cache=self.cache).start()
+        session = AdaptiveSession.create(spec, cache=self.cache)
+        session.query = qid
+        return session.start()
 
     def _admit(self) -> Tuple[List[str], bool]:
         """Admission stage: lease a submesh per queued query (FIFO) until
@@ -239,7 +245,7 @@ class EpochScheduler:
                     break            # FIFO: the head waits for capacity
             self._queue.popleft()
             self._note_lease(qid, lease)
-            self._active[qid] = self._materialize(item, lease)
+            self._active[qid] = self._materialize(qid, item, lease)
             self._admitted_tick[qid] = self.tick_count
             admitted.append(qid)
         return admitted, blocked_on_placement
@@ -308,6 +314,7 @@ class EpochScheduler:
         return events
 
     # ----------------------------------------------------------- the tick
+    @span("scheduler.tick")
     def tick(self) -> TickEvents:
         """One scheduling quantum: relieve placement pressure → admit (lease
         a submesh per query) → step every in-flight query one epoch on its

@@ -24,7 +24,6 @@ chunked, CRC'd, atomic-rename) with the spec in the manifest ``meta`` —
 from __future__ import annotations
 
 import dataclasses
-import time
 from pathlib import Path
 from typing import Any, Dict, Optional, Tuple
 
@@ -39,6 +38,7 @@ from ..core.epoch import EpochConfig
 from ..core.frames import FrameStrategy
 from ..core.instances import BuiltInstance, get_instance
 from ..core.substrate import EpochStepper, make_stepper
+from ..runtime.spans import count, span
 
 PyTree = Any
 
@@ -149,7 +149,8 @@ class StepperCache:
     def get(self, spec: SessionSpec) -> Tuple[BuiltInstance, EpochStepper]:
         key = spec.stepper_key()
         if key not in self._cache:
-            self._cache[key] = _build(spec)
+            with span("session.build", instance=spec.instance):
+                self._cache[key] = _build(spec)
         return self._cache[key]
 
     def __len__(self) -> int:
@@ -205,6 +206,11 @@ class AdaptiveSession:
         s.save(ckpt_dir)              # any epoch boundary
         r = AdaptiveSession.restore(ckpt_dir)
         # r continues bit-identically to an uninterrupted s
+
+    Host spans ``session.init`` and ``session.step`` (children
+    ``session.dispatch`` and ``session.readback``) carry ``query``; each
+    ends once the verdict is on the host, and counts what the sampler's
+    counters gained in it.
     """
 
     def __init__(self, spec: SessionSpec, built: BuiltInstance,
@@ -213,7 +219,24 @@ class AdaptiveSession:
         self.built = built
         self.stepper = stepper
         self.state = None
-        self.wall_s = 0.0             # host-measured time spent stepping
+        self.query = f"{spec.instance}:{spec.seed}"   # the spans' query id
+        # seconds in session.init and session.step spans: from the dispatch
+        # until the verdict is on the host
+        self.wall_s = 0.0
+
+    @property
+    def state(self):
+        return self._state
+
+    @state.setter
+    def state(self, state) -> None:
+        self._state = state
+        self._host = None             # (active, counters), read back once
+
+    def _readback(self) -> tuple:
+        if self._host is None:
+            self._host = self.stepper.readback(self._state)
+        return self._host
 
     @classmethod
     def create(cls, spec: SessionSpec,
@@ -244,9 +267,11 @@ class AdaptiveSession:
 
     # ------------------------------------------------------------- running
     def start(self) -> "AdaptiveSession":
-        t0 = time.perf_counter()
-        self.state = self.stepper.init(self.spec.seed)
-        self.wall_s += time.perf_counter() - t0
+        with span("session.init", query=self.query) as s:
+            self.state = self.stepper.init(self.spec.seed)
+            for name, value in self._readback()[1].items():
+                count(name, value)
+        self.wall_s += s.seconds
         return self
 
     @property
@@ -255,7 +280,7 @@ class AdaptiveSession:
 
     @property
     def done(self) -> bool:
-        return self.started and not self.stepper.active(self.state)
+        return self.started and not self._readback()[0]
 
     @property
     def epoch(self) -> int:
@@ -273,9 +298,16 @@ class AdaptiveSession:
         assert self.started, "call start() (or restore) first"
         if self.done:
             return True
-        t0 = time.perf_counter()
-        self.state = self.stepper.step(self.state, self.spec.seed)
-        self.wall_s += time.perf_counter() - t0
+        before = self._readback()[1]
+        with span("session.step", query=self.query) as s:
+            with span("session.dispatch"):
+                state = self.stepper.step(self.state, self.spec.seed)
+            with span("session.readback"):
+                self.state = state
+                after = self._readback()[1]
+            for name, value in after.items():
+                count(name, value - before.get(name, 0))
+        self.wall_s += s.seconds
         return self.done
 
     def run(self) -> "AdaptiveSession":

@@ -34,7 +34,7 @@ def np_bfs(g, s):
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_bfs_matches_numpy(seed):
     g = erdos_renyi(80, 200, seed=seed)
-    dist, sigma = bfs_sssp(g, jnp.int32(5), None, max_levels=g.n,
+    dist, sigma, _ = bfs_sssp(g, jnp.int32(5), None, max_levels=g.n,
                            early_exit=False)
     nd, ns = np_bfs(g, 5)
     dj = np.asarray(dist)
@@ -63,7 +63,7 @@ def test_sample_path_distribution_uniform():
     #   0 - 1 - 3
     #    \- 2 -/
     g = from_edges(4, np.array([[0, 1], [0, 2], [1, 3], [2, 3]]))
-    dist, sigma = bfs_sssp(g, jnp.int32(0), jnp.int32(3), max_levels=5,
+    dist, sigma, _ = bfs_sssp(g, jnp.int32(0), jnp.int32(3), max_levels=5,
                            early_exit=False)
     keys = jax.random.split(jax.random.key(0), 400)
     masks = jax.vmap(lambda k: sample_path(
@@ -84,7 +84,7 @@ def test_sample_path_weighted_by_sigma():
     # extra shortest path? Keep the diamond + pentagon mix simple:
     g = from_edges(6, np.array([
         [0, 1], [0, 2], [1, 3], [2, 3], [3, 4], [0, 5], [5, 4]]))
-    dist, sigma = bfs_sssp(g, jnp.int32(0), jnp.int32(4), max_levels=6,
+    dist, sigma, _ = bfs_sssp(g, jnp.int32(0), jnp.int32(4), max_levels=6,
                            early_exit=False)
     # σ(4): via 3 (2 paths) + via 5 (1 path) at dist 3? dist(4)=2 via 5,
     # dist via 3 is 3 — so only the 0-5-4 path is shortest; check that:
@@ -98,7 +98,7 @@ def test_sample_path_weighted_by_sigma():
 
 def test_disconnected_pair_contributes_zero():
     g = from_edges(4, np.array([[0, 1], [2, 3]]))
-    dist, sigma = bfs_sssp(g, jnp.int32(0), jnp.int32(3), max_levels=5,
+    dist, sigma, _ = bfs_sssp(g, jnp.int32(0), jnp.int32(3), max_levels=5,
                            early_exit=False)
     mask = sample_path(g, jax.random.key(0), jnp.int32(0), jnp.int32(3),
                        dist, sigma, max_len=4)

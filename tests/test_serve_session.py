@@ -13,7 +13,9 @@ The acceptance obligations of the serving subsystem:
   memory drops to Θ(n/W′).
 """
 
+import dataclasses
 import functools
+import time
 
 import jax
 import numpy as np
@@ -207,3 +209,32 @@ def test_elastic_rejects_invalid():
     sh.start()
     with pytest.raises(ValueError, match="divide"):
         reshard_session(sh, 3)
+
+
+def test_wall_s_covers_the_step_until_its_state_is_ready():
+    """``wall_s`` counts a step until its verdict is on the host: a step
+    whose device work takes 0.3 s after its dispatch has returned is
+    charged all of it, and its state is ready when ``step()`` returns."""
+    s = AdaptiveSession.create(SessionSpec(INSTANCE, "local", seed=11),
+                               cache=CACHE).start()
+    real = s.stepper.step_fn
+
+    def late(x):
+        time.sleep(0.3)
+        return x
+
+    delay = jax.jit(lambda x: jax.pure_callback(
+        late, jax.ShapeDtypeStruct(x.shape, x.dtype), x))
+    def step_fn(state, seed):
+        out = real(state, seed)
+        return out._replace(stop=delay(out.stop))
+
+    s.stepper = dataclasses.replace(s.stepper, step_fn=step_fn)
+    s.step()                      # compiles the delay
+    wall0, t0 = s.wall_s, time.perf_counter()
+    s.step()
+    t1 = time.perf_counter()
+    jax.block_until_ready(s.state)
+    t2 = time.perf_counter()
+    assert 0.3 <= s.wall_s - wall0 <= t1 - t0
+    assert t2 - t1 < 0.1

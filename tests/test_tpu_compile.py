@@ -21,7 +21,7 @@ from repro.core.epoch import EpochConfig, run_sharded
 from repro.core.frames import FrameStrategy
 from repro.core.instances import KadabraInstance
 from repro.graphs import erdos_renyi
-from repro.graphs.kadabra import Preprocessed, make_sample_fn
+from repro.graphs.kadabra import Preprocessed, init_counters, make_sample_fn
 from repro.sampling.alias import AliasTable, make_weighted_sample_fn
 
 # G of chip_smoke.py: its graph, and the BFS level / path-length bounds
@@ -69,7 +69,7 @@ def test_kadabra_round_fits_one_chip(one_chip):
                            diam_levels=G_VD)
         sample_fn = make_sample_fn(g, pre, 8)
         keys = jax.random.split(jax.random.key(seed), 4)
-        frames, _ = jax.vmap(lambda k: sample_fn(k, None))(keys)
+        frames, _ = jax.vmap(lambda k: sample_fn(k, init_counters()))(keys)
         return frames.data
 
     compiled = jax.jit(sample_round).lower(g_spec, comps, seed).compile()
@@ -114,7 +114,7 @@ def test_run_sharded_compiles_on_four_chips(topo, strategy, frame_shards):
 
     def run():
         st = run_sharded(built.sample_fn, built.check_fn, built.template,
-                         None, 0, mesh, "workers", cfg,
+                         built.init_carry, 0, mesh, "workers", cfg,
                          frame_shards=frame_shards)
         return st.total.num
 
