@@ -115,30 +115,35 @@ def sample_path(g: Graph, key: jax.Array, s: jax.Array, t: jax.Array,
     dist[u] = dist[cur]−1) with probability σ(u)/Σσ via Gumbel-max over the
     ≤ max_degree padded neighbor slots.  If t is unreachable the mask is all
     False (the sample contributes x_i = 0 — the correct estimator term).
-    The walk takes ``max_len`` steps, each the named scope ``path_step``;
-    the first ``dist[t]`` of them are live, the rest keep the walk at s.
+    Step i draws from ``split(key, max_len)[i]``, and each step is the named
+    scope ``path_step``.  The walk stops once it is at s, after ``dist[t]``
+    steps (none if t is unreachable, at most ``max_len``).  Under ``vmap``
+    the batched loop runs the deepest lane's steps and the finished lanes
+    keep their mask.
     """
     n = g.n
     reachable = dist[t] != INF
     dist_pad = jnp.concatenate([dist, jnp.full((1,), INF, jnp.int32)])
     sigma_pad = jnp.concatenate([sigma, jnp.zeros((1,), jnp.float32)])
+    keys = jax.random.split(key, max_len)
+
+    def walking(carry):
+        i, cur, _ = carry
+        return (i < max_len) & (cur != s) & reachable
 
     @jax.named_scope("path_step")
-    def step(carry, k):
-        cur, mask = carry
-        done = jnp.logical_or(cur == s, ~reachable)
+    def step(carry):
+        i, cur, mask = carry
         nbrs = g.neighbors_padded(cur)                  # (Δ,) with sentinel n
         w = jnp.where(dist_pad[nbrs] == dist[cur] - 1, sigma_pad[nbrs], 0.0)
         gum = -jnp.log(-jnp.log(
-            jax.random.uniform(k, w.shape, minval=1e-12, maxval=1.0)))
+            jax.random.uniform(keys[i], w.shape, minval=1e-12, maxval=1.0)))
         scores = jnp.where(w > 0.0, jnp.log(w) + gum, -jnp.inf)
         nxt = nbrs[jnp.argmax(scores)]
-        cur2 = jnp.where(done, cur, nxt)
-        is_internal = jnp.logical_and(cur2 != s, cur2 != t)
-        mask = mask.at[cur2].set(jnp.where(
-            jnp.logical_and(~done, is_internal), True, mask[cur2]))
-        return (cur2, mask), None
+        is_internal = jnp.logical_and(nxt != s, nxt != t)
+        mask = mask.at[nxt].set(jnp.logical_or(mask[nxt], is_internal))
+        return i + 1, nxt, mask
 
-    keys = jax.random.split(key, max_len)
-    (_, mask), _ = jax.lax.scan(step, (t, jnp.zeros((n,), bool)), keys)
-    return jnp.where(reachable, mask, False)
+    _, _, mask = jax.lax.while_loop(
+        walking, step, (jnp.int32(0), t, jnp.zeros((n,), bool)))
+    return mask

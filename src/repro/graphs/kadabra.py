@@ -75,7 +75,8 @@ def init_counters() -> dict:
     ``rounds`` sampling rounds; ``bfs_levels`` levels the batched BFS loop
     ran, the deepest lane's in each round; ``path_live_steps`` path-walk
     steps that moved, ``dist(s, t)`` of each lane whose t was reachable;
-    ``path_steps`` path-walk steps taken, ``batch × max_len`` a round.
+    ``path_steps`` the path walk's step budget, ``batch × max_len`` a round
+    (the walk stops earlier, with the round's deepest lane).
     """
     return {name: jnp.int32(0) for name in COUNTERS}
 

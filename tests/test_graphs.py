@@ -1,12 +1,15 @@
 """Graph substrate: BFS/σ counting vs numpy, CC, path-sampling distribution."""
 
+from functools import partial
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.graphs import (bfs_sssp, connected_components, eccentricity,
-                          erdos_renyi, from_edges, grid2d, sample_path)
+from repro.graphs import (barabasi_albert, bfs_sssp, connected_components,
+                          eccentricity, erdos_renyi, from_edges, grid2d,
+                          sample_path)
 from repro.graphs.bfs import INF
 
 
@@ -103,3 +106,77 @@ def test_disconnected_pair_contributes_zero():
     mask = sample_path(g, jax.random.key(0), jnp.int32(0), jnp.int32(3),
                        dist, sigma, max_len=4)
     assert not np.asarray(mask).any()
+
+
+def _scan_walk(g, key, s, t, dist, sigma, *, max_len):
+    """The walk as a fixed ``max_len``-step scan: step i draws from
+    ``split(key, max_len)[i]``, and a lane at s (or with t unreachable)
+    keeps its place and mask.  The oracle of the early-stopping walk."""
+    reachable = dist[t] != INF
+    dist_pad = jnp.concatenate([dist, jnp.full((1,), INF, jnp.int32)])
+    sigma_pad = jnp.concatenate([sigma, jnp.zeros((1,), jnp.float32)])
+
+    def step(carry, k):
+        cur, mask = carry
+        done = jnp.logical_or(cur == s, ~reachable)
+        nbrs = g.neighbors_padded(cur)
+        w = jnp.where(dist_pad[nbrs] == dist[cur] - 1, sigma_pad[nbrs], 0.0)
+        gum = -jnp.log(-jnp.log(
+            jax.random.uniform(k, w.shape, minval=1e-12, maxval=1.0)))
+        scores = jnp.where(w > 0.0, jnp.log(w) + gum, -jnp.inf)
+        nxt = nbrs[jnp.argmax(scores)]
+        cur2 = jnp.where(done, cur, nxt)
+        is_internal = jnp.logical_and(cur2 != s, cur2 != t)
+        mask = mask.at[cur2].set(jnp.where(
+            jnp.logical_and(~done, is_internal), True, mask[cur2]))
+        return (cur2, mask), None
+
+    keys = jax.random.split(key, max_len)
+    (_, mask), _ = jax.lax.scan(step, (t, jnp.zeros((g.n,), bool)), keys)
+    return jnp.where(reachable, mask, False)
+
+
+def _walk_case(case):
+    """(graph, s, t) of 32 lanes for one case of the walk's oracle test."""
+    lanes = 32
+    rng = np.random.default_rng(7)
+    if case == "two_components":
+        a = barabasi_albert(1500, 2, seed=1)
+        e = np.stack([np.asarray(a.src), np.asarray(a.dst)], axis=1)
+        g = from_edges(3000, np.concatenate([e, e + 1500]))
+    else:
+        g = barabasi_albert(3000, 2, seed=0)
+    s = rng.integers(0, g.n, lanes)
+    if case == "adjacent":
+        idx = np.asarray(g.indices_padded)
+        t = idx[np.asarray(g.indptr)[s]]
+    else:
+        t = (s + 1 + rng.integers(0, g.n - 1, lanes)) % g.n
+    return g, jnp.asarray(s, jnp.int32), jnp.asarray(t, jnp.int32)
+
+
+@pytest.mark.parametrize("case", ["hubs", "two_components", "adjacent",
+                                  "max_len_is_deepest"])
+def test_sample_path_stops_early_as_the_fixed_scan(case):
+    g, s, t = _walk_case(case)
+    dist, sigma, _ = jax.vmap(lambda a, b: bfs_sssp(
+        g, a, b, max_levels=g.n, early_exit=True))(s, t)
+    d = np.asarray(jax.vmap(lambda row, b: row[b])(dist, t))
+    reachable = d != int(INF)
+    deepest = int(d[reachable].max())
+    max_len = deepest if case == "max_len_is_deepest" else 17
+    assert deepest <= max_len
+    keys = jax.random.split(jax.random.key(3), len(s))
+    walks = [jax.jit(jax.vmap(partial(w, g, max_len=max_len)))(
+        keys, s, t, dist, sigma) for w in (sample_path, _scan_walk)]
+    got, want = (np.asarray(m) for m in walks)
+    np.testing.assert_array_equal(got, want)
+    # each reachable lane's mask holds the dist(s, t) − 1 internal vertices
+    np.testing.assert_array_equal(got.sum(axis=1),
+                                  np.where(reachable, d - 1, 0))
+    if case == "two_components":
+        assert 0 < reachable.sum() < len(s)
+    elif case == "adjacent":
+        assert (d == 1).all()
+    else:
+        assert reachable.all() and deepest >= 3
