@@ -2,7 +2,8 @@
 each found by its name in a file of its own under ``bench/``.
 
 * ``bench/configs/<config>.json`` — the deployment: graph generator and its
-  parameters, the query, the layout on the chips.
+  parameters and the query.  The layout is the cell's: one worker per chip
+  (``Cell.chips``), sequential on one chip and under ``shard_map`` on more.
 * ``bench/traffic/<traffic>.json`` — the traffic mix.
 * ``bench/graphs/<generator>.py`` — ``generate(config, seed) -> (n, edges)``.
 * ``bench/metrics/<metric>.py`` — ``read(ctx) -> float | None``.
@@ -30,6 +31,12 @@ class Cell:
     traffic: dict
     end_to_end: tuple   # the BENCHMARK.json entries this cell reports
     per_layer: tuple
+
+    @property
+    def substrate(self) -> str:
+        """The program's substrate for the workers: one worker runs
+        sequentially, several under ``shard_map``, one on each chip."""
+        return "sequential" if self.chips == 1 else "shard_map"
 
 
 def _one(items: list, what: str) -> dict:

@@ -78,7 +78,8 @@ class Reference:
 
     def __init__(self, win):
         cfg = win.cell.config
-        self.world, self.batch = int(cfg["world"]), int(cfg["batch"])
+        # one worker per chip
+        self.world, self.batch = win.cell.chips, int(cfg["batch"])
         self.rounds = win.instance.rounds_per_epoch
         self.eps, self.delta = float(cfg["eps"]), float(cfg["delta"])
         self.g = ref.build_graph(win.n, win.edges)

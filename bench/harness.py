@@ -1,10 +1,11 @@
 """One run of a cell: set-up, the measured window and what the check reads.
 
 The window drives the program's per-epoch serving path: an
-``AdaptiveSession`` over ``SessionSpec(<config>, strategy, W, seed)`` with
-its cached compiled ``EpochStepper``, stepped one epoch at a time until
-``seconds`` have passed; the window ends when the epoch in flight at that
-moment has finished.  The traffic is a closed loop of one query at a time:
+``AdaptiveSession`` over ``SessionSpec(<config>, strategy, W, seed)``, W
+workers one per chip of the cell, with its cached compiled
+``EpochStepper``, stepped one epoch at a time until ``seconds`` have
+passed; the window ends when the epoch in flight at that moment has
+finished.  The traffic is a closed loop of one query at a time:
 when a query retires, the next starts at the next seed on the same stepper.
 """
 
@@ -190,8 +191,9 @@ def measure(setup: Setup, devices: list, *, seed: int, seconds: float,
     cfg = cell.config
 
     def start(qseed: int) -> dict:
-        spec = SessionSpec(inst.name, cfg["strategy"], int(cfg["world"]),
-                           qseed, substrate=cfg["substrate"])
+        # one worker per chip
+        spec = SessionSpec(inst.name, cfg["strategy"], cell.chips, qseed,
+                           substrate=cell.substrate)
         session = AdaptiveSession.create(spec, setup.cache).start()
         return {"seed": qseed, "session": session,
                 "frames": [session.state.pending], "tau0": 0}

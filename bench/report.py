@@ -36,7 +36,7 @@ def per_layer(win, trace_dir) -> tuple[dict, dict]:
     trace = tr.load(trace_dir, tr.hlo_op_names(win.hlo_text))
     lo, hi = trace.window()
     per_epoch = (int(cfg["batch"]) * win.instance.rounds_per_epoch
-                 * int(cfg["world"]))
+                 * win.cell.chips)   # one worker per chip
     epochs = win.samples / per_epoch
     ctx = Context(trace=trace, lo=lo, hi=hi, epochs=epochs,
                   rounds=epochs * win.instance.rounds_per_epoch,

@@ -1,12 +1,13 @@
 """A run of a cell at a tiny size on the CPU, with the chip look skipped
-and, on request, a fault planted in the program underneath it.  With four
-workers it is the one-chip cell's query laid out as the paper's parallel
-algorithm, one worker per device under ``shard_map``.
+and, on request, a fault planted in the program underneath it.  The
+harness lays the workers out from the cell's chips, one per device: on four
+virtual CPU devices the one-chip cell's query runs as four workers under
+``shard_map``, the paper's layout.
 
-    python -m bench.tests.tiny <world> <fault>    # prints the numbers as JSON
+    python -m bench.tests.tiny <chips> <fault> <graph>   # prints JSON
 
 ``<fault>`` is one of FAULTS, or ``control`` for the control in the
-program's place.
+program's place; ``<graph>`` one of GRAPHS.
 """
 
 from __future__ import annotations
@@ -59,24 +60,34 @@ def plant(fault: str, patch) -> None:
         raise ValueError(fault)
 
 
-def values(world: int, *, control: bool = False, seed: int = 2**31 + 17,
+RGG = {"generator": "rgg", "radius_factor": 0.55}   # DIMACS10 rgg_n_2_<k>_s0
+# the tiny graphs, as ``values``'s keyword arguments
+GRAPHS = {"g500": {}, "rgg": {"graph": RGG, "scale": 10}}
+
+
+def values(chips: int = 1, *, graph: dict | None = None,
+           control: bool = False, seed: int = 2**31 + 17,
            scale: int = 9) -> dict:
+    """The numbers the check compares for a run of the one-chip cell at a
+    tiny scale on ``chips`` devices, its graph's parameters replaced by
+    ``graph`` where given; the harness lays the workers out, one a chip."""
     import jax
 
     from bench import cell as cellmod, check, harness
 
-    cell = cellmod.load("g500-bc.1chip")
-    layout = {} if world == 1 else {"substrate": "shard_map"}
-    cell = dataclasses.replace(cell, chips=world, config=dict(
-        cell.config, scale=scale, name=f"tiny-w{world}", world=world,
-        **layout))
-    win = harness.run(cell, jax.devices(), seed=seed, seconds=1.0,
+    graph = graph or {}
+    c = cellmod.load("g500-bc.1chip")
+    c = dataclasses.replace(c, chips=chips, config=dict(
+        c.config, scale=scale, name=f"tiny-{graph.get('generator', 'g500')}"
+        f"-w{chips}", **graph))
+    win = harness.run(c, jax.devices()[:c.chips], seed=seed, seconds=1.0,
                       t_process=time.perf_counter())
     return check.compare(win, check.Reference(win), control=control)
 
 
 if __name__ == "__main__":
-    world, fault = int(sys.argv[1]), sys.argv[2]
+    chips, fault, graph = int(sys.argv[1]), sys.argv[2], sys.argv[3]
     if fault != "control":
         plant(fault, setattr)
-    print(json.dumps(values(world, control=fault == "control")))
+    print(json.dumps(values(chips, **GRAPHS[graph],
+                            control=fault == "control")))
